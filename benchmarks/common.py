@@ -9,6 +9,7 @@ import numpy as np
 from repro.data import FederatedData, dirichlet_partition, iid_partition, \
     make_classification_data
 from repro.fl import FLConfig, FLServer, MLPTask
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_env(n_devices: int = 40, k: int = 5, rounds: int = 25, l_ep: int = 3,
@@ -31,6 +32,7 @@ def build_env(n_devices: int = 40, k: int = 5, rounds: int = 25, l_ep: int = 3,
     the attack scenarios of repro.fl.attacks; ``observe`` is the
     ``FLConfig.observe`` recorder spec (``make_server`` accepts a per-run
     override, so sweep drivers can trace each run to its own directory)."""
+    enable_compile_cache()
     train, test = make_classification_data(n_samples=n_samples, seed=seed)
     if sigma is None:
         parts = iid_partition(len(train.y), n_devices, seed=seed, size_skew=0.8)

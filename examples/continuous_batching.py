@@ -10,11 +10,13 @@ import jax
 import numpy as np
 
 from repro.configs import get_model_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.scheduler import ContinuousBatcher, Request
 from repro.models import transformer as T
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b")
     ap.add_argument("--slots", type=int, default=3)

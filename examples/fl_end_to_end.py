@@ -34,6 +34,7 @@ from repro.data import (
 )
 from repro.fl import FLConfig, FLServer, LMTask, MLPTask, available_executors, \
     available_scenarios, build_policy
+from repro.launch.compile_cache import enable_compile_cache
 
 POLICY_NAMES = ("fedavg", "afl", "tifl", "oort", "favor", "fedmarl", "fedrank")
 
@@ -56,6 +57,7 @@ def build_lm_fl_data(cfg, n_clients: int, seq: int = 32, seed: int = 0):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=25)
     ap.add_argument("--devices", type=int, default=40)

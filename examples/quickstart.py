@@ -14,6 +14,9 @@ gaps (docs/architecture.md).
 from repro.core import augment_demonstrations, collect_demonstrations, pretrain_qnet
 from repro.data import FederatedData, dirichlet_partition, make_classification_data
 from repro.fl import FLConfig, FLServer, MLPTask, build_policy
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # 1. a federated dataset: 30 clients, Dirichlet(0.1) non-IID labels
 train, test = make_classification_data(n_samples=8000, seed=0)

@@ -100,8 +100,12 @@ def flash_attention_folded(
     window: Optional[int] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
+    """``interpret=None`` resolves to interpret mode off-TPU and compiled
+    mode on TPU."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     bkv, sg, dh = q.shape
     s = k.shape[1]
     block_q = min(block_q, sg)

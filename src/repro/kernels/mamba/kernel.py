@@ -67,8 +67,12 @@ def _largest_divisor(n: int, cap: int = 128) -> int:
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def selective_scan_pallas(x: jnp.ndarray, dt: jnp.ndarray, Bm: jnp.ndarray,
                           Cm: jnp.ndarray, A: jnp.ndarray, h0: jnp.ndarray,
-                          *, chunk: int = 64, interpret: bool = True
+                          *, chunk: int = 64, interpret: bool = None
                           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``interpret=None`` resolves to interpret mode off-TPU and compiled
+    mode on TPU."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     b, t, inner = x.shape
     state = A.shape[1]
     chunk = min(chunk, t)

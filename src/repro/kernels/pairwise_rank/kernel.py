@@ -7,8 +7,9 @@ score vectors each loaded once per tile row/column (HBM traffic O(N^2/BN)
 instead of materializing the N^2 matrices), MXU-aligned BN=128 lanes, and a
 scalar accumulator revisited across the sequential TPU grid.
 
-Grid: (N/BN, N/BN); outputs (sum, count) accumulate in a (1,1) block that
-every grid step revisits (legal on TPU: grid iterations are sequential).
+Grid: (N/BN, N/BN); outputs (sum, count) accumulate in a (1,1) SMEM block
+that every grid step revisits (legal on TPU: grid iterations are
+sequential).
 """
 from __future__ import annotations
 
@@ -17,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_BLOCK = 128
@@ -89,7 +91,9 @@ def pairwise_rank_pallas(scores: jnp.ndarray, targets: jnp.ndarray,
 
     row_spec = pl.BlockSpec((1, block), lambda i, j: (0, i))
     col_spec = pl.BlockSpec((1, block), lambda i, j: (0, j))
-    out_spec = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
+    # scalar accumulators live in SMEM: Mosaic cannot store scalars to VMEM
+    out_spec = pl.BlockSpec((1, 1), lambda i, j: (0, 0),
+                            memory_space=pltpu.SMEM)
 
     out_sum, out_cnt = pl.pallas_call(
         functools.partial(_kernel, block=block, hard=hard),

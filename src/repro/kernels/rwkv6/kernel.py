@@ -73,8 +73,12 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sf_ref,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def wkv6_pallas(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 logw: jnp.ndarray, u: jnp.ndarray, s0: jnp.ndarray,
-                *, chunk: int = 64, interpret: bool = True
+                *, chunk: int = 64, interpret: bool = None
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``interpret=None`` resolves to interpret mode off-TPU and compiled
+    mode on TPU."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     bh, t, n = r.shape
     chunk = min(chunk, t)
     assert t % chunk == 0, (t, chunk)

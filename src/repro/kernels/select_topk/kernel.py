@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.select_topk.ref import NEG_INF
+from repro.kernels.select_topk.ref import HIGHEST, NEG_INF
 
 DEFAULT_BLOCK = 512
 _INT32_MAX = 2**31 - 1  # plain int: jnp constants can't be captured by kernels
@@ -57,15 +57,13 @@ def _kernel(f_ref, m_ref, b_ref, w1_ref, b1_ref, w2_ref, b2_ref, w3_ref,
 
     # --- fused Q-net MLP head over this tile (MXU) ---------------------
     feats = f_ref[:].astype(jnp.float32)                       # (block, F)
-    h = jax.nn.relu(jax.lax.dot_general(
-        feats, w1_ref[:], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) + b1_ref[0, :][None, :])
-    h = jax.nn.relu(jax.lax.dot_general(
-        h, w2_ref[:], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) + b2_ref[0, :][None, :])
-    s = jax.lax.dot_general(
-        h, w3_ref[:], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[:, 0] + b3_ref[0, 0]  # (block,)
+    dot = functools.partial(jax.lax.dot_general,
+                            dimension_numbers=(((1,), (0,)), ((), ())),
+                            precision=HIGHEST,
+                            preferred_element_type=jnp.float32)
+    h = jax.nn.relu(dot(feats, w1_ref[:]) + b1_ref[0, :][None, :])
+    h = jax.nn.relu(dot(h, w2_ref[:]) + b2_ref[0, :][None, :])
+    s = dot(h, w3_ref[:])[:, 0] + b3_ref[0, 0]                 # (block,)
     s = s + b_ref[0, :].astype(jnp.float32)
     s = jnp.where(m_ref[0, :] > 0, s, NEG_INF)
     gidx = t * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)[0]
@@ -73,13 +71,15 @@ def _kernel(f_ref, m_ref, b_ref, w1_ref, b1_ref, w2_ref, b2_ref, w3_ref,
     # --- merge tile scores into the carried top-K ----------------------
     work_v = jnp.concatenate([vals_ref[0, :], s])       # (k_pad + block,)
     work_i = jnp.concatenate([idx_ref[0, :], gidx])
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, k_pad), 1)[0]
 
     def extract(j, carry):
         wv, wi, ov, oi = carry
         vmax = jnp.max(wv)
         imin = jnp.min(jnp.where(wv == vmax, wi, _INT32_MAX))
-        ov = jax.lax.dynamic_update_slice(ov, vmax[None], (j,))
-        oi = jax.lax.dynamic_update_slice(oi, imin[None], (j,))
+        # masked write of slot j: Mosaic does not lower dynamic_update_slice
+        ov = jnp.where(slot == j, vmax, ov)
+        oi = jnp.where(slot == j, imin, oi)
         kill = wi == imin                       # indices are unique
         wv = jnp.where(kill, NEG_INF, wv)
         wi = jnp.where(kill, _INT32_MAX, wi)    # retire from tie-breaking

@@ -32,14 +32,21 @@ import jax.numpy as jnp
 # oracle bit-identical on the masked tail.
 NEG_INF = -3.0e38
 
+# Full-f32 MXU passes for the scoring matmuls in the oracle AND the kernel:
+# at DEFAULT precision XLA rounds f32 operands to one bf16 pass on TPU, and
+# a 1e-3 score error reorders near-tied candidates between the two paths.
+# Off-TPU f32 dots are exact either way.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def qnet_scores_ref(params, feats: jnp.ndarray) -> jnp.ndarray:
     """feats (N, F) -> scores (N,): the Q-net 3-layer MLP head, identical
     math to ``repro.core.qnet.apply_qnet``."""
     f = feats.astype(jnp.float32)
-    h = jax.nn.relu(f @ params["w1"] + params["b1"])
-    h = jax.nn.relu(h @ params["w2"] + params["b2"])
-    return (h @ params["w3"] + params["b3"])[..., 0]
+    dot = functools.partial(jnp.dot, precision=HIGHEST)
+    h = jax.nn.relu(dot(f, params["w1"]) + params["b1"])
+    h = jax.nn.relu(dot(h, params["w2"]) + params["b2"])
+    return (dot(h, params["w3"]) + params["b3"])[..., 0]
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

@@ -6,15 +6,10 @@ state; only the dry-run entrypoint forces the 512-device host platform.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _mk(shape, axes) -> Mesh:
-    try:
-        from jax.sharding import AxisType
-    except ImportError:      # older jax: meshes are Auto-typed already
-        return jax.make_mesh(shape, axes)
-
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 
@@ -26,9 +21,11 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     return _mk(shape, axes)
 
 
-def make_host_mesh() -> Mesh:
-    """Single-device mesh with the same axis names (CPU tests)."""
-    return _mk((1, 1), ("data", "model"))
+def make_host_mesh(n_data: int = 1) -> Mesh:
+    """``(n_data, 1)`` mesh over the first ``n_data`` of ``jax.devices()``
+    with the production axis names: 1 is the single-device CPU-test mesh,
+    4 shards the FL client axis over the four chips of one v5e host."""
+    return _mk((n_data, 1), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh: Mesh) -> dict:

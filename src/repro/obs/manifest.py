@@ -78,8 +78,10 @@ def run_manifest(cfg=None, scenario: Optional[str] = None,
     try:
         import jax
         backend = jax.default_backend()
+        devices = jax.devices()
+        device_kind, device_count = devices[0].device_kind, len(devices)
     except Exception:
-        backend = None
+        backend = device_kind = device_count = None
     doc = {
         "schema_version": SCHEMA_VERSION,
         "created_at": time.strftime("%Y-%m-%dT%H:%M:%S%z"),
@@ -88,6 +90,8 @@ def run_manifest(cfg=None, scenario: Optional[str] = None,
             "system": platform.system(),
             "machine": platform.machine(),
             "backend": backend,
+            "device_kind": device_kind,
+            "device_count": device_count,
         },
         "versions": _versions(),
     }

@@ -339,6 +339,15 @@ def test_config_digest_ignores_observe(tmp_path):
     assert "observe" not in man["config"]
 
 
+def test_manifest_names_the_device():
+    import jax
+
+    plat = run_manifest()["platform"]
+    assert plat["backend"] == jax.default_backend()
+    assert plat["device_kind"] == jax.devices()[0].device_kind
+    assert plat["device_count"] == len(jax.devices())
+
+
 def test_make_recorder_dispatch(tmp_path):
     assert make_recorder(None) is NULL_RECORDER
     assert make_recorder(False) is NULL_RECORDER

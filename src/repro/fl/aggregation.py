@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.profiling import count_d2h, count_implicit_h2d
+
 Params = Any
 
 STALENESS_KINDS = ("constant", "polynomial", "hinge")
@@ -36,6 +38,9 @@ AGGREGATORS = ("mean", "trimmed_mean", "coordinate_median", "krum",
 
 def fedavg(client_params: Sequence[Params], weights: Sequence[float]) -> Params:
     """Data-size-weighted parameter average (McMahan et al., 2017)."""
+    # host (NumPy) updates merge on the host unless a device leaf is among
+    # them; the counter is a no-op unless a profiler is active
+    count_implicit_h2d("merge", *client_params)
     w = np.asarray(weights, np.float64)
     w = w / w.sum()
 
@@ -78,6 +83,7 @@ def trimmed_mean(client_params: Sequence[Params], weights: Sequence[float],
 
     def combine(*leaves):
         stack = jnp.stack([l.astype(jnp.float32) for l in leaves], axis=0)
+        count_implicit_h2d("merge", stack, *leaves)
         # per-coordinate rank of each client via double argsort (stable)
         ranks = jnp.argsort(jnp.argsort(stack, axis=0), axis=0)
         keep = (ranks >= trim) & (ranks < m - trim)
@@ -102,6 +108,7 @@ def coordinate_median(client_params: Sequence[Params]) -> Params:
 
     def combine(*leaves):
         stack = jnp.stack([l.astype(jnp.float32) for l in leaves], axis=0)
+        count_implicit_h2d("merge", stack, *leaves)
         return jnp.median(stack, axis=0).astype(leaves[0].dtype)
 
     return jax.tree.map(combine, *client_params)
@@ -114,6 +121,7 @@ def krum_scores(client_params: Sequence[Params], f: int = 1) -> np.ndarray:
     their nearest peers are still far away.  Distances accumulate in
     float64 on host so scores are deterministic across backends."""
     m = len(client_params)
+    count_d2h("merge", *client_params)
     flat = np.stack([
         np.concatenate([np.asarray(l, np.float64).ravel()
                         for l in jax.tree.leaves(p)])
@@ -258,6 +266,7 @@ def buffered_aggregate(global_params: Params,
         shrink = float(((w / w.sum()) * s).sum())
         reduced = robust_aggregate(client_params, w * s, kind=robust,
                                    trim=trim, f=f, m_select=m_select)
+        count_implicit_h2d("merge", global_params, reduced)
         return jax.tree.map(
             lambda g, r: (g.astype(jnp.float32) * (1.0 - shrink)
                           + r.astype(jnp.float32) * shrink).astype(g.dtype),
@@ -265,6 +274,8 @@ def buffered_aggregate(global_params: Params,
     coef = (w / w.sum()) * s
     if kind == "constant":
         return fedavg(client_params, data_weights)
+    # host client leaves cross when they meet a device global model
+    count_implicit_h2d("merge", global_params, *client_params)
 
     def combine(g, *leaves):
         acc = g.astype(jnp.float32) * (1.0 - coef.sum())

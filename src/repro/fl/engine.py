@@ -49,7 +49,13 @@ from repro.fl.client import (
     local_train,
     make_parallel_local_train,
 )
-from repro.obs.profiling import timed_call
+from repro.obs.profiling import (
+    count_d2h,
+    count_h2d,
+    count_implicit_h2d,
+    span,
+    timed_call,
+)
 
 Params = Any
 
@@ -124,6 +130,12 @@ class ExecutionResult:
     params: Dict[int, Params] = field(default_factory=dict)
     losses: Dict[int, np.ndarray] = field(default_factory=dict)
 
+    def block_until_ready(self) -> "ExecutionResult":
+        """Fence every client's params (``jax.block_until_ready`` calls
+        this on a result, as ``timed_call`` does around an executor)."""
+        jax.block_until_ready(self.params)
+        return self
+
 
 # Seed strides for per-client local-training RNG: stage seeds are
 # ``cfg.seed + stride * round + client_id`` so probe and completion stages
@@ -147,10 +159,11 @@ def build_requests(ids: Sequence[int], client_data: Callable[[int], tuple],
     two paths cannot drift in seeds or request shape.
     """
     init = init_params or {}
-    return [ClientRequest(int(i), *client_data(int(i)), epochs=epochs,
-                          seed=seed + stride * round_idx + int(i),
-                          init_params=init.get(int(i)))
-            for i in ids]
+    with span("train.gather"):
+        return [ClientRequest(int(i), *client_data(int(i)), epochs=epochs,
+                              seed=seed + stride * round_idx + int(i),
+                              init_params=init.get(int(i)))
+                for i in ids]
 
 
 class ClientExecutor(Protocol):
@@ -239,58 +252,74 @@ class VmappedExecutor:
 
     def _run_bucket(self, task, global_params, reqs, cap, epochs, out, *,
                     lr, batch_size, prox_mu):
+        # sub-spans and transfer counters (repro.obs.profiling) are no-ops
+        # unless a profiler is active
         _, bs, nb = _bucket_geometry(cap, batch_size)
         take = nb * bs
         k = len(reqs)
-        xs, ys, masks, perms = [], [], [], []
-        for req in reqs:
-            xpad, ypad, mask = _pad_bucket(req.x, req.y)
-            xs.append(xpad)
-            ys.append(ypad)
-            masks.append(mask)
-            rng = np.random.default_rng(req.seed)
-            perms.append(np.stack([rng.permutation(cap)[:take]
-                                   for _ in range(epochs)]).astype(np.int32))
-        stacked_init = any(req.init_params is not None for req in reqs)
-        inits = ([req.init_params if req.init_params is not None
-                  else global_params for req in reqs] if stacked_init else None)
-        # pad the client axis up to a multiple of the mesh data-axis size
-        # (duplicates of the last client; results discarded) so sharding
-        # never silently degrades to replicated execution
-        n_pad = (-k) % self._mesh_axis_size() if self.mesh is not None else 0
-        for _ in range(n_pad):
-            for lst in (xs, ys, masks, perms):
-                lst.append(lst[-1])
+        with span("train.pack"):
+            xs, ys, masks, perms = [], [], [], []
+            for req in reqs:
+                xpad, ypad, mask = _pad_bucket(req.x, req.y)
+                xs.append(xpad)
+                ys.append(ypad)
+                masks.append(mask)
+                rng = np.random.default_rng(req.seed)
+                perms.append(np.stack([rng.permutation(cap)[:take]
+                                       for _ in range(epochs)]).astype(np.int32))
+            stacked_init = any(req.init_params is not None for req in reqs)
+            inits = ([req.init_params if req.init_params is not None
+                      else global_params for req in reqs]
+                     if stacked_init else None)
+            # pad the client axis up to a multiple of the mesh data-axis
+            # size (duplicates of the last client; results discarded) so
+            # sharding never silently degrades to replicated execution
+            n_pad = (-k) % self._mesh_axis_size() if self.mesh is not None else 0
+            for _ in range(n_pad):
+                for lst in (xs, ys, masks, perms):
+                    lst.append(lst[-1])
+                if stacked_init:
+                    inits.append(inits[-1])
+            xs, ys, masks, perms = (np.stack(a) for a in (xs, ys, masks, perms))
             if stacked_init:
-                inits.append(inits[-1])
-        xs = jnp.asarray(np.stack(xs))
-        ys = jnp.asarray(np.stack(ys))
-        masks = jnp.asarray(np.stack(masks))
-        perms = jnp.asarray(np.stack(perms))
-        if stacked_init:
-            p0 = jax.tree.map(
-                lambda *ls: jnp.asarray(np.stack([np.asarray(l) for l in ls])),
-                *inits)
-        else:
-            # shared start (probe stage / vanilla rounds): pass the single
-            # pytree and let vmap broadcast it inside XLA — no K-fold copy
-            p0 = global_params
-        step = _bucket_step(task, bs, nb, epochs, float(prox_mu), stacked_init)
-        xs, ys, masks, perms = self._shard((xs, ys, masks, perms))
-        p0 = self._shard_params(p0, stacked_init)
+                count_d2h("train", inits)
+                p0 = jax.tree.map(
+                    lambda *ls: np.stack([np.asarray(l) for l in ls]), *inits)
+        with span("train.upload"):
+            xs, ys, masks, perms = (jnp.asarray(a) for a in (xs, ys, masks, perms))
+            if stacked_init:
+                p0 = jax.tree.map(jnp.asarray, p0)
+                count_h2d("train", xs, ys, masks, perms, p0)
+            else:
+                # shared start (probe stage / vanilla rounds): pass the
+                # single pytree and let vmap broadcast it inside XLA — no
+                # K-fold copy; host leaves cross as the step's operands
+                p0 = global_params
+                count_h2d("train", xs, ys, masks, perms)
+                count_implicit_h2d("train", p0, xs)
+            step = _bucket_step(task, bs, nb, epochs, float(prox_mu),
+                                stacked_init)
+            xs, ys, masks, perms = self._shard((xs, ys, masks, perms))
+            p0 = self._shard_params(p0, stacked_init)
+            lr = jnp.asarray(lr, jnp.float32)
+            count_h2d("train", lr)
         # timed_call is a passthrough unless a profiler is active
         # (repro.obs.profiling), in which case the jitted bucket step is
         # fenced and charged per (cohort-size, epochs) geometry
         stacked, ep_losses = timed_call(
             f"vmapped.bucket_step[k={len(reqs)},ep={epochs}]",
-            step, p0, xs, ys, masks, jnp.asarray(lr, jnp.float32), perms)
+            step, p0, xs, ys, masks, lr, perms)
         # one device->host transfer per leaf, then cheap numpy views per
         # client — slicing on device would cost K x leaves dispatches
-        stacked = jax.tree.map(np.asarray, stacked)
-        ep_losses = np.asarray(ep_losses)
-        for j, req in enumerate(reqs):
-            out.params[req.client_id] = jax.tree.map(lambda a, j=j: a[j], stacked)
-            out.losses[req.client_id] = ep_losses[j]
+        with span("train.pull"):
+            count_d2h("train", stacked, ep_losses)
+            stacked = jax.tree.map(np.asarray, stacked)
+            ep_losses = np.asarray(ep_losses)
+        with span("train.split"):
+            for j, req in enumerate(reqs):
+                out.params[req.client_id] = jax.tree.map(lambda a, j=j: a[j],
+                                                         stacked)
+                out.losses[req.client_id] = ep_losses[j]
 
     def _mesh_axis_size(self) -> int:
         """Size of the mesh ``data`` axis (buckets are padded to a multiple)."""

@@ -356,6 +356,10 @@ class FLServer:
         self.attack = (cfg.attack if cfg.attack is not None
                        else getattr(self.pool, "attack", None))
         self.rng = np.random.default_rng(cfg.seed + 17)
+        # an earlier server's recorder must not time, fence or count this
+        # server's work: only this server's own enabled recorder (set
+        # below) is the active profiler
+        _profiling.clear_profiler()
         from repro.core.features import get_feature_set   # deferred: repro.core
         #                                                   imports repro.fl
 
@@ -390,6 +394,7 @@ class FLServer:
         self.log = StructuredLogger(level=cfg.log_level or None,
                                     recorder=self.obs)
         self._executor_label = executor_label(self.executor)
+        self._executor_op = f"executor.{self._executor_label}"
         if self.obs.enabled:
             _profiling.set_profiler(self.obs)
 
@@ -420,8 +425,16 @@ class FLServer:
         with getattr(self, "obs", NULL_RECORDER).span("evaluate"):
             for i in range(0, len(te.y), bs):
                 b = {"x": jnp.asarray(te.x[i:i + bs]), "y": jnp.asarray(te.y[i:i + bs])}
-                accs.append(float(self._eval_fn(self.global_params, b)) * len(b["y"]))
-                losses.append(float(self._loss_fn(self.global_params, b)) * len(b["y"]))
+                # transfer counters: no-ops unless a profiler is active
+                _profiling.count_h2d("evaluate", b)
+                _profiling.count_implicit_h2d("evaluate", self.global_params, b)
+                acc = self._eval_fn(self.global_params, b)
+                _profiling.count_d2h("evaluate", acc)
+                accs.append(float(acc) * len(b["y"]))
+                _profiling.count_implicit_h2d("evaluate", self.global_params, b)
+                loss = self._loss_fn(self.global_params, b)
+                _profiling.count_d2h("evaluate", loss)
+                losses.append(float(loss) * len(b["y"]))
                 n += len(b["y"])
         return sum(accs) / n, sum(losses) / n
 
@@ -450,20 +463,13 @@ class FLServer:
         return self.data.train.x[idx], self.data.train.y[idx]
 
     def _execute(self, requests: Sequence[ClientRequest]):
-        if not self.obs.enabled:
-            return self.executor.run(self.task, self.global_params, requests,
-                                     lr=self.cfg.lr, batch_size=self.cfg.local_batch,
-                                     prox_mu=self.cfg.prox_mu)
-        # profiled path: fence the result so device work is charged to this
-        # executor call rather than the next host sync
-        t0 = time.perf_counter()
-        out = self.executor.run(self.task, self.global_params, requests,
-                                lr=self.cfg.lr, batch_size=self.cfg.local_batch,
-                                prox_mu=self.cfg.prox_mu)
-        jax.block_until_ready(out.params)
-        self.obs.record_op(f"executor.{self._executor_label}",
-                           time.perf_counter() - t0)
-        return out
+        # a passthrough unless a profiler is active: then the result is
+        # fenced, so device work is charged to this executor call rather
+        # than the next host sync, and the call is a span of its op name
+        return _profiling.timed_call(
+            self._executor_op, self.executor.run, self.task,
+            self.global_params, requests, lr=self.cfg.lr,
+            batch_size=self.cfg.local_batch, prox_mu=self.cfg.prox_mu)
 
     def _check_available(self, ctx: RoundContext, ids: np.ndarray,
                          policy: SelectionPolicy, stage: str) -> None:

@@ -39,7 +39,12 @@ import numpy as np
 
 from repro.kernels.select_topk.kernel import select_topk_pallas
 from repro.kernels.select_topk.ref import NEG_INF, select_topk_ref
-from repro.obs.profiling import timed_call
+from repro.obs.profiling import (
+    count_d2h,
+    count_h2d,
+    count_implicit_h2d,
+    timed_call,
+)
 
 
 def resolve_select_impl(impl: str = "auto") -> str:
@@ -120,17 +125,22 @@ def select_topk(scores_fn: Union[dict, Callable[[np.ndarray], np.ndarray], None]
         feats = jnp.asarray(states, jnp.float32)
         mj = jnp.asarray(m, jnp.float32)
         bj = jnp.asarray(b)
+        # transfer counters: no-ops unless a profiler is active
+        count_h2d("select", feats, mj, bj)
+        count_implicit_h2d("select", scores_fn, feats)
         # timed_call is a passthrough unless a profiler is active
-        # (repro.obs.profiling): then the call is block_until_ready-fenced
-        # and its wall-clock lands in the run record's op table
+        # (repro.obs.profiling): then the call is a span of its op name,
+        # block_until_ready-fenced, and its wall-clock lands in the run
+        # record's op table
         if resolve_select_impl(impl) == "pallas":
             vals, idx = timed_call("select_topk.pallas", select_topk_pallas,
                                    scores_fn, feats, mj, bj, k=min(int(k), n))
         else:
             vals, idx = timed_call("select_topk.xla", select_topk_ref,
                                    scores_fn, feats, mj, bj, k=min(int(k), n))
-        return (np.asarray(idx[:k_eff], np.int64),
-                np.asarray(vals[:k_eff], np.float32))
+        idx, vals = idx[:k_eff], vals[:k_eff]
+        count_d2h("select", idx, vals)
+        return np.asarray(idx, np.int64), np.asarray(vals, np.float32)
 
     def _host_select():
         scores = states if scores_fn is None else np.asarray(scores_fn(states))

@@ -10,7 +10,9 @@ The opt-in instrumentation layer for both round engines (``FLConfig
   round (devices online, buffer fill, staleness distribution, per-tier
   lag, adversaries merged, events per window).
 * :mod:`repro.obs.profiling` — ``block_until_ready`` timing around
-  executor and kernel calls, plus the ``jax.profiler`` trace gate.
+  executor and kernel calls, sub-spans for code below the server,
+  host↔device transfer and compile counters, plus the ``jax.profiler``
+  trace gate.
 * :mod:`repro.obs.manifest` — the reproducibility header (config digest,
   scenario, seed, platform, package versions).
 * :mod:`repro.obs.log` — the structured logger behind the engines' round

@@ -21,7 +21,10 @@ Two implementations of one small protocol:
 Span records carry BOTH clocks: ``wall_s`` (host ``perf_counter`` delta)
 and, when a virtual clock was supplied, ``v0_s``/``v1_s`` (virtual time at
 enter/exit).  Nesting is recorded as a ``/``-joined path ("aggregate/
-evaluate"), in exit order (children before parents).
+evaluate"), in exit order (children before parents).  An enabled span also
+enters ``jax.profiler.TraceAnnotation("fl:<name>")``, so in any profiler
+session (:func:`repro.obs.profiling.trace_gate`, ``REPRO_JAX_TRACE``) the
+program's phases label the host timeline beside the device's.
 """
 from __future__ import annotations
 
@@ -29,6 +32,8 @@ import json
 import os
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.metrics import NULL_METRICS, MetricsRegistry
 
@@ -73,7 +78,7 @@ NULL_RECORDER = NullRecorder()
 
 
 class _Span:
-    __slots__ = ("rec", "name", "clock", "t0", "v0")
+    __slots__ = ("rec", "name", "clock", "t0", "v0", "note")
 
     def __init__(self, rec: "RunRecorder", name: str,
                  clock: Optional[Callable[[], float]]):
@@ -82,6 +87,8 @@ class _Span:
         self.clock = clock
 
     def __enter__(self):
+        self.note = TraceAnnotation("fl:" + self.name)
+        self.note.__enter__()
         self.rec._stack.append(self.name)
         self.v0 = self.clock() if self.clock is not None else None
         self.t0 = time.perf_counter()
@@ -97,6 +104,7 @@ class _Span:
             entry["v0_s"] = float(self.v0)
             entry["v1_s"] = float(self.clock())
         rec._spans.append(entry)
+        self.note.__exit__(*exc)
         return False
 
 
@@ -139,6 +147,10 @@ class RunRecorder:
         callable sampled at enter/exit (the async engines pass
         ``lambda: engine.now``); host wall-time is always recorded."""
         return _Span(self, name, clock)
+
+    def span_path(self) -> str:
+        """``/``-joined names of the spans open now ("" outside any)."""
+        return "/".join(self._stack)
 
     # -- structured events (interleave with round records) -------------
     def event(self, name: str, **fields) -> None:

@@ -396,3 +396,203 @@ def test_observed_server_registers_profiler(mlp_task, fl_data):
     # the executor op timing landed in the round record, attributed by label
     assert f"executor.{hist[0].executor}" in rounds[0]["ops"]
     assert rounds[0]["executor"] == hist[0].executor
+
+
+# ---------------------------------------------------------------------------
+# the host<->device boundary: train.* sub-spans, transfer and compile
+# counters, fl: annotations
+# ---------------------------------------------------------------------------
+SYNC_TOP = {"plan", "probe", "select", "complete", "aggregate", "telemetry",
+            "evaluate", "observe"}
+ASYNC_TOP = {"ready_check", "aggregate", "dispatch", "events"}
+TRAIN_SUBSPANS = {"train.gather", "train.pack", "train.upload", "train.pull",
+                  "train.split"}
+
+
+def _round_records(rec):
+    return [r for r in rec.records if r.get("type") == "round"]
+
+
+def _observed_vmapped_run(mlp_task, fl_data, mode, policy, rec, **kw):
+    cfg = dict(n_devices=20, k_select=3, rounds=2, l_ep=2, lr=0.1, seed=7,
+               scenario="high-churn", executor="vmapped", observe=rec)
+    if mode == "async":
+        cfg.update(mode="async", async_concurrency=6, staleness="polynomial")
+    cfg.update(kw)
+    srv = FLServer(FLConfig(**cfg), mlp_task, fl_data)
+    pol_kw = {"k": 3, "seed": 7} if policy == "fedrank" else {}
+    srv.run(build_policy(policy, **pol_kw))
+    return srv
+
+
+@pytest.mark.parametrize("mode,policy,stages", [
+    ("sync", "fedrank", ("probe", "complete")),
+    ("async", "fedavg", ("dispatch",)),
+])
+def test_vmapped_round_records_train_subspans(mlp_task, fl_data, mode,
+                                              policy, stages):
+    rec = RunRecorder()
+    srv = _observed_vmapped_run(mlp_task, fl_data, mode, policy, rec)
+    label = srv._executor_label
+    tops = set()
+    for r in _round_records(rec):
+        paths = {sp["span"] for sp in r["spans"]}
+        tops |= {p for p in paths if "/" not in p}
+        for stage in stages:
+            assert stage in paths
+            # train.gather under the stage; the rest under the executor's
+            # timed_call span, beside the fenced bucket step
+            assert f"{stage}/train.gather" in paths
+            ex = f"{stage}/executor.{label}"
+            assert ex in paths
+            for sub in TRAIN_SUBSPANS - {"train.gather"}:
+                assert f"{ex}/{sub}" in paths
+            assert any(p.startswith(f"{ex}/vmapped.bucket_step[")
+                       for p in paths)
+    # the sub-spans nest: the top-level phases are the engines' own
+    assert tops == (SYNC_TOP if mode == "sync" else ASYNC_TOP)
+
+
+def _cohort_h2d(requests, batch_size, params_host_bytes=0):
+    """The bytes the vmapped executor uploads for ``requests``: per bucket
+    the padded rows, labels, mask and shuffle orders, the learning rate,
+    and the shared start's host leaves."""
+    from repro.fl.client import _bucket_geometry, _pad_bucket
+
+    buckets = {}
+    for req in requests:
+        cap, _, _ = _bucket_geometry(len(req.y), batch_size)
+        buckets.setdefault((cap, req.epochs), []).append(req)
+    total = 0
+    for (cap, epochs), reqs in buckets.items():
+        _, bs, nb = _bucket_geometry(cap, batch_size)
+        for req in reqs:
+            xpad, ypad, mask = _pad_bucket(req.x, req.y)
+            total += xpad.nbytes + ypad.nbytes + mask.nbytes
+            total += epochs * nb * bs * 4          # int32 shuffle orders
+        total += 4 + params_host_bytes             # float32 lr, start
+    return total, len(buckets)
+
+
+@pytest.mark.parametrize("host_params", [False, True])
+def test_h2d_train_counts_the_cohort(mlp_task, fl_data, host_params):
+    import jax
+    import numpy as np
+
+    from repro.fl.engine import VmappedExecutor, build_requests
+
+    params = mlp_task.init(jax.random.PRNGKey(0))
+    if host_params:
+        params = jax.tree.map(np.asarray, params)
+    host_bytes = (sum(l.nbytes for l in jax.tree.leaves(params))
+                  if host_params else 0)
+    ids = [0, 3, 5, 11]
+
+    def rows(i):
+        idx = fl_data.client_indices[i]
+        return fl_data.train.x[idx], fl_data.train.y[idx]
+
+    reqs = build_requests(ids, rows, 2, seed=7, round_idx=0, stride=1000)
+    rec = RunRecorder()
+    set_profiler(rec)
+    VmappedExecutor().run(mlp_task, params, reqs, lr=0.1, batch_size=10,
+                          prox_mu=0.0)
+    rec.flush_round(round=0, mode="sync", host_time_s=0.0)
+    counters = rec.records[0]["metrics"]["counters"]
+    want, n_buckets = _cohort_h2d(reqs, 10, host_bytes)
+    assert counters["h2d_bytes.train"] == want
+    # one blocking read per stacked leaf and one for the losses, per bucket
+    n_leaves = len(jax.tree.leaves(params))
+    assert counters["host_syncs.train"] == n_buckets * (n_leaves + 1)
+
+
+def test_host_syncs_evaluate_two_per_batch(mlp_task, fl_data):
+    rec = RunRecorder()
+    _observed_vmapped_run(mlp_task, fl_data, "sync", "fedavg", rec, rounds=1)
+    (r,) = _round_records(rec)
+    counters = r["metrics"]["counters"]
+    n_batches = -(-len(fl_data.test.y) // 512)
+    assert counters["host_syncs.evaluate"] == 2 * n_batches
+    # at least the evaluation rows crossed (plus any host-held params)
+    rows = fl_data.test.x.nbytes + fl_data.test.y.nbytes
+    assert counters["h2d_bytes.evaluate"] >= rows
+
+
+def test_compile_counter_names_the_span_path():
+    import jax
+    import numpy as np
+
+    rec = RunRecorder()
+    set_profiler(rec)
+    f = jax.jit(lambda x: x * 3.0 - 1.0)
+    x = np.ones(37, np.float32)
+    with rec.span("outer"):
+        with rec.span("inner"):
+            f(x)
+            f(x)                         # same shape: cached, no compile
+    with rec.span("outer"):
+        f(x)
+    rec.flush_round(round=0, mode="sync", host_time_s=0.0)
+    counters = rec.records[0]["metrics"]["counters"]
+    assert counters == {"compiles.outer/inner": 1}
+
+
+def test_enabled_span_enters_a_trace_annotation(monkeypatch):
+    import repro.obs.recorder as recorder
+
+    seen = []
+
+    class Note:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(recorder, "TraceAnnotation", Note)
+    rec = RunRecorder()
+    with rec.span("aggregate"):
+        with rec.span("evaluate"):
+            pass
+    assert seen == [("enter", "fl:aggregate"), ("enter", "fl:evaluate"),
+                    ("exit", "fl:evaluate"), ("exit", "fl:aggregate")]
+    seen.clear()
+    with NULL_RECORDER.span("aggregate"):
+        pass
+    assert seen == []
+
+
+def test_unobserved_run_counts_nothing(mlp_task, fl_data, monkeypatch):
+    from repro.obs import profiling
+
+    clear_profiler()
+    assert profiling.span("train.pack") is NULL_RECORDER.span("x")
+
+    def boom(*_):
+        raise AssertionError("byte counting ran with observability off")
+
+    monkeypatch.setattr(profiling, "_tree_nbytes", boom)
+    monkeypatch.setattr(profiling, "_leaves", boom)
+    _observed_vmapped_run(mlp_task, fl_data, "sync", "fedrank", None)
+    _observed_vmapped_run(mlp_task, fl_data, "async", "fedavg", None)
+
+
+def test_unobserved_server_clears_a_stale_profiler(mlp_task, fl_data):
+    stale = RunRecorder()
+    _observed_vmapped_run(mlp_task, fl_data, "sync", "fedavg", stale,
+                          rounds=1)
+    assert active_profiler() is stale
+    n_records = len(stale.records)
+    srv = FLServer(FLConfig(n_devices=20, k_select=3, rounds=1, l_ep=2,
+                            seed=7, scenario="high-churn",
+                            executor="vmapped"), mlp_task, fl_data)
+    assert active_profiler() is None
+    srv.run(build_policy("fedavg"))
+    stale.flush_round(round=99, mode="sync", host_time_s=0.0)
+    # nothing of the second server's run reached the earlier recorder
+    (last,) = stale.records[n_records:]
+    assert last["spans"] == [] and last["ops"] == {}
+    assert last["metrics"]["counters"] == {}

@@ -136,10 +136,10 @@ def run_fl_executor_bench(ks=(4, 8, 16, 32), rounds: int = 3,
             per_round[executor] = (time.perf_counter() - t0) / rounds
             # stage-level: executor.run alone, isolating client execution
             # from eval/selection/cost accounting shared by both executors
-            from repro.fl.engine import ClientRequest
+            from repro.fl.engine import build_requests
 
-            reqs = [ClientRequest(i, *srv._client_data(i), epochs=l_ep, seed=i)
-                    for i in range(k)]
+            reqs = build_requests(range(k), data, l_ep, seed=0, round_idx=0,
+                                  stride=0)
             srv._execute(reqs)                 # warmup for this shape
             t0 = time.perf_counter()
             for _ in range(rounds):

@@ -1,7 +1,7 @@
 """Federated data container + batch iterators."""
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Iterator, List, Tuple
 
 import numpy as np
@@ -16,10 +16,46 @@ class FederatedData:
     train: SyntheticClassificationDataset
     test: SyntheticClassificationDataset
     client_indices: List[np.ndarray]
+    # placement (None or a Mesh) -> the device-resident training rows
+    _resident: dict = field(default_factory=dict, init=False, repr=False,
+                            compare=False)
 
     @property
     def n_clients(self) -> int:
         return len(self.client_indices)
+
+    @property
+    def pad_row(self) -> int:
+        """Index of the all-zero row :meth:`resident_train` appends: every
+        padded slot of a client's shard reads it."""
+        return len(self.train.y)
+
+    def resident_train(self, mesh=None):
+        """``(x, y)``: the training rows on the device with one all-zero row
+        appended at :attr:`pad_row`, uploaded once per placement (the
+        default device, or replicated over ``mesh``) and kept here, since
+        the rows never change.  A set larger than device memory fails
+        here, at the upload."""
+        hit = self._resident.get(mesh)
+        if hit is None:
+            import jax
+
+            from repro.obs.profiling import count_resident_upload
+
+            x, y = self.train.x, self.train.y
+            x = np.concatenate([x, np.zeros((1,) + x.shape[1:], x.dtype)])
+            y = np.concatenate([y, np.zeros((1,) + y.shape[1:], y.dtype)])
+            if mesh is None:
+                hit = (jax.device_put(x), jax.device_put(y))
+            else:
+                from jax.sharding import NamedSharding, PartitionSpec as P
+
+                replicated = NamedSharding(mesh, P())
+                hit = (jax.device_put(x, replicated),
+                       jax.device_put(y, replicated))
+            count_resident_upload(*hit)
+            self._resident[mesh] = hit
+        return hit
 
     def client_size(self, k: int) -> int:
         return len(self.client_indices[k])

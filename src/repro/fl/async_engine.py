@@ -391,7 +391,7 @@ class AsyncRoundEngine:
 
         if plan.has_probe:
             srv._check_available(ctx, probe_ids, self.policy, "probed")
-            reqs = build_requests(probe_ids, srv._client_data,
+            reqs = build_requests(probe_ids, srv.data,
                                   plan.probe_epochs, seed=cfg.seed,
                                   round_idx=self.cycle,
                                   stride=PROBE_SEED_STRIDE)
@@ -419,7 +419,7 @@ class AsyncRoundEngine:
         # result *lands*.  One batched executor call per wave.
         losses: Dict[int, np.ndarray] = {}
         if plan.completion_epochs > 0 and len(selected):
-            reqs = build_requests(selected, srv._client_data,
+            reqs = build_requests(selected, srv.data,
                                   plan.completion_epochs, seed=cfg.seed,
                                   round_idx=self.cycle,
                                   stride=COMPLETE_SEED_STRIDE,

@@ -4,9 +4,10 @@ All entry points are jit-compiled once per (task, padded-size) bucket; client
 datasets are padded to power-of-two buckets with a validity mask so the jit
 cache stays small across heterogeneous client sizes.
 
-``parallel_local_train`` is the pod-scale path: K clients' local training as
-one vmapped/pjit-able step (clients on the mesh ``data`` axis) — the TPU-
-native analogue of the paper's multi-process simulator.
+``make_parallel_local_train`` is the pod-scale path: K clients' local
+training as one vmapped/pjit-able step (clients on the mesh ``data`` axis)
+over the device-resident training rows, each client's batches gathered by
+row index — the TPU-native analogue of the paper's multi-process simulator.
 """
 from __future__ import annotations
 
@@ -124,8 +125,17 @@ def probing_epoch(task, params: Params, x: np.ndarray, y: np.ndarray, *,
 def make_parallel_local_train(task, *, batch_size: int, n_batches: int,
                               epochs: int, prox_mu: float = 0.0,
                               stacked_params: bool = False) -> Callable:
-    """Returns f(init_params, xs (K, cap, ...), ys, masks, lr[, perms])
-    -> (stacked client params (K, ...), per-epoch mean losses (K, epochs)).
+    """Returns f(init_params, x, y, rows (K, epochs, n_batches*batch_size),
+    lr) -> (stacked client params (K, ...), per-epoch mean losses (K, epochs)).
+
+    ``x`` / ``y`` are the whole training set, resident on the device, with
+    an all-zero padding row last (:meth:`FederatedData.resident_train`);
+    they are shared operands, not stacked per client.  ``rows`` holds each
+    client's per-epoch batches as absolute row indices, already in the
+    shuffled order of :func:`local_train`; padded slots point at the
+    padding row, which also masks them out of the loss.  Each epoch
+    gathers its rows inside the step, so the batches hold exactly the
+    values :func:`local_train`'s padded, shuffled shard holds.
 
     vmap over the client axis; under pjit the K axis is sharded over the mesh
     ``data`` axis, so each chip simulates a slice of the cohort.
@@ -135,15 +145,12 @@ def make_parallel_local_train(task, *, batch_size: int, n_batches: int,
       probe-stage output); otherwise the single pytree is broadcast.  The
       FedProx proximal term anchors to each client's own init params — the
       same semantics as the sequential :func:`local_train`.
-    * ``perms`` (K, epochs, n_batches*batch_size) int32 optionally supplies
-      per-client per-epoch shuffle orders (gathered inside the jit), letting
-      callers reproduce the host-side shuffling of :func:`local_train`
-      exactly.  When omitted, every epoch scans the shards in storage order.
     * ``losses[:, 0]`` is the probe loss the FedRank scheme reports.
     """
-    take = n_batches * batch_size
 
-    def one_client(p_init, x, y, mask, lr, perm):
+    def one_client(p_init, x, y, rows, lr):
+        pad_row = x.shape[0] - 1
+
         def prox_loss(p, batch):
             l = task.loss(p, batch)
             if prox_mu > 0.0:
@@ -160,23 +167,21 @@ def make_parallel_local_train(task, *, batch_size: int, n_batches: int,
                                ).astype(p.dtype), params, g)
             return params, loss
 
-        def epoch(params, pe):
-            xe, ye, me = x[pe], y[pe], mask[pe]
+        def epoch(params, re):
+            xe, ye = x[re], y[re]
+            me = (re != pad_row).astype(jnp.float32)
             xs = (xe.reshape((n_batches, batch_size) + x.shape[1:]),
                   ye.reshape((n_batches, batch_size) + y.shape[1:]),
                   me.reshape((n_batches, batch_size)))
             params, losses = jax.lax.scan(sgd_step, params, xs)
             return params, losses.mean()
 
-        params, ep_losses = jax.lax.scan(epoch, p_init, perm)
+        params, ep_losses = jax.lax.scan(epoch, p_init, rows)
         return params, ep_losses
 
-    def parallel(p_init, xs, ys, masks, lr, perms=None):
-        if perms is None:
-            perms = jnp.broadcast_to(jnp.arange(take, dtype=jnp.int32),
-                                     (xs.shape[0], epochs, take))
+    def parallel(p_init, x, y, rows, lr):
         return jax.vmap(one_client,
-                        in_axes=(0 if stacked_params else None, 0, 0, 0, None, 0))(
-            p_init, xs, ys, masks, lr, perms)
+                        in_axes=(0 if stacked_params else None, None, None, 0,
+                                 None))(p_init, x, y, rows, lr)
 
     return parallel

@@ -13,13 +13,14 @@ work is executed:
 * :class:`ClientExecutor` — the protocol for running a batch of per-client
   local-training requests.  :class:`SequentialExecutor` is the reference
   implementation (one :func:`repro.fl.client.local_train` call per client,
-  the seed repo's semantics).  :class:`VmappedExecutor` pads clients into
+  the seed repo's semantics).  :class:`VmappedExecutor` groups clients into
   power-of-two size buckets and runs each bucket's cohort as ONE
   jitted/vmapped step via :func:`repro.fl.client.make_parallel_local_train`
-  — optionally sharding the client axis over a mesh ``data`` axis
-  (``repro.launch.mesh``), which is the TPU pod-scale path.  Both executors
-  replay identical per-client shuffle orders, so they produce numerically
-  matching global models.
+  over the device-resident training rows, staging only each client's
+  shuffled row indices — optionally sharding the client axis over a mesh
+  ``data`` axis (``repro.launch.mesh``), which is the TPU pod-scale path.
+  Both executors replay identical per-client shuffle orders, so they
+  produce numerically matching global models.
 
 Executors are looked up by name (``FLConfig.executor``) through a small
 registry so new execution backends (remote, failure-injecting) plug in
@@ -45,7 +46,6 @@ import numpy as np
 
 from repro.fl.client import (
     _bucket_geometry,
-    _pad_bucket,
     local_train,
     make_parallel_local_train,
 )
@@ -113,14 +113,23 @@ def build_round_plan(policy, ctx, l_ep: int) -> RoundPlan:
 
 @dataclass(frozen=True)
 class ClientRequest:
-    """One client's local-training work item for a stage."""
+    """One client's local-training work item for a stage: its rows of the
+    data source by index (``x`` / ``y`` gather them on the host)."""
 
     client_id: int
-    x: np.ndarray
-    y: np.ndarray
+    data: Any                              # FederatedData the rows index
+    rows: np.ndarray                       # row indices into data.train
     epochs: int
     seed: int
     init_params: Optional[Params] = None   # None => start from global params
+
+    @property
+    def x(self) -> np.ndarray:
+        return self.data.train.x[self.rows]
+
+    @property
+    def y(self) -> np.ndarray:
+        return self.data.train.y[self.rows]
 
 
 @dataclass
@@ -146,21 +155,23 @@ PROBE_SEED_STRIDE = 1000
 COMPLETE_SEED_STRIDE = 2000
 
 
-def build_requests(ids: Sequence[int], client_data: Callable[[int], tuple],
-                   epochs: int, *, seed: int, round_idx: int, stride: int,
+def build_requests(ids: Sequence[int], data, epochs: int, *, seed: int,
+                   round_idx: int, stride: int,
                    init_params: Optional[Dict[int, Params]] = None
                    ) -> List[ClientRequest]:
     """Shared dispatch interface: one :class:`ClientRequest` per client id.
 
-    ``client_data(i) -> (x, y)`` supplies each client's shard;
-    ``init_params`` (id -> params) overrides the global starting point for
-    clients that resume from probed state.  Both the synchronous server and
-    the asynchronous engine build their stages through this function, so the
-    two paths cannot drift in seeds or request shape.
+    ``data`` (a :class:`~repro.data.loader.FederatedData`) supplies each
+    client's shard as its row indices (``data.client_indices[i]``, not a
+    copy); ``init_params`` (id -> params) overrides the global starting
+    point for clients that resume from probed state.  Both the synchronous
+    server and the asynchronous engine build their stages through this
+    function, so the two paths cannot drift in seeds or request shape.
     """
     init = init_params or {}
     with span("train.gather"):
-        return [ClientRequest(int(i), *client_data(int(i)), epochs=epochs,
+        return [ClientRequest(int(i), data, data.client_indices[int(i)],
+                              epochs=epochs,
                               seed=seed + stride * round_idx + int(i),
                               init_params=init.get(int(i)))
                 for i in ids]
@@ -220,11 +231,15 @@ def _bucket_step(task, batch_size: int, n_batches: int, epochs: int,
 class VmappedExecutor:
     """Pod-scale path: the whole cohort's local training as one jitted step.
 
-    Clients are grouped into (padded-size, epochs) buckets; each bucket is a
-    single vmapped call over the client axis, with host-side shuffle orders
-    fed in as gather indices so results match :class:`SequentialExecutor`
-    numerically.  Pass a ``Mesh`` (see :mod:`repro.launch.mesh`) to shard
-    the client axis over the mesh ``data`` axis.
+    Clients are grouped into (data source, padded-size, epochs) buckets;
+    each bucket is a single vmapped call over the client axis.  The
+    training rows stay on the device (uploaded once per data source and
+    placement, :meth:`FederatedData.resident_train`); a stage uploads only
+    each client's host-drawn shuffle orders composed with its row indices,
+    which the step gathers by, so results match
+    :class:`SequentialExecutor` numerically.  Pass a ``Mesh`` (see
+    :mod:`repro.launch.mesh`) to shard the client axis over the mesh
+    ``data`` axis; the resident rows are then replicated.
     """
 
     name = "vmapped"
@@ -243,9 +258,9 @@ class VmappedExecutor:
                 out.params[req.client_id] = init
                 out.losses[req.client_id] = np.zeros(0)
                 continue
-            cap, _, _ = _bucket_geometry(len(req.y), batch_size)
-            buckets.setdefault((cap, req.epochs), []).append(req)
-        for (cap, epochs), reqs in buckets.items():
+            cap, _, _ = _bucket_geometry(len(req.rows), batch_size)
+            buckets.setdefault((id(req.data), cap, req.epochs), []).append(req)
+        for (_, cap, epochs), reqs in buckets.items():
             self._run_bucket(task, global_params, reqs, cap, epochs, out,
                              lr=lr, batch_size=batch_size, prox_mu=prox_mu)
         return out
@@ -256,50 +271,51 @@ class VmappedExecutor:
         # unless a profiler is active
         _, bs, nb = _bucket_geometry(cap, batch_size)
         take = nb * bs
+        data = reqs[0].data
         k = len(reqs)
+        # pad the client axis up to a multiple of the mesh data-axis size
+        # (duplicates of the last client; results discarded) so sharding
+        # never silently degrades to replicated execution
+        n_pad = (-k) % self._mesh_axis_size() if self.mesh is not None else 0
         with span("train.pack"):
-            xs, ys, masks, perms = [], [], [], []
-            for req in reqs:
-                xpad, ypad, mask = _pad_bucket(req.x, req.y)
-                xs.append(xpad)
-                ys.append(ypad)
-                masks.append(mask)
+            # each client's shuffle orders, drawn as local_train draws them,
+            # composed with its row indices; a padded slot reads the
+            # all-zero padding row, as local_train's zero padding
+            rows = np.empty((k + n_pad, epochs, take), np.int32)
+            shard = np.empty(cap, np.int32)
+            for j, req in enumerate(reqs):
+                n = len(req.rows)
+                shard[:n] = req.rows
+                shard[n:] = data.pad_row
                 rng = np.random.default_rng(req.seed)
-                perms.append(np.stack([rng.permutation(cap)[:take]
-                                       for _ in range(epochs)]).astype(np.int32))
+                for e in range(epochs):
+                    rows[j, e] = shard[rng.permutation(cap)[:take]]
+            rows[k:] = rows[k - 1]
             stacked_init = any(req.init_params is not None for req in reqs)
-            inits = ([req.init_params if req.init_params is not None
-                      else global_params for req in reqs]
-                     if stacked_init else None)
-            # pad the client axis up to a multiple of the mesh data-axis
-            # size (duplicates of the last client; results discarded) so
-            # sharding never silently degrades to replicated execution
-            n_pad = (-k) % self._mesh_axis_size() if self.mesh is not None else 0
-            for _ in range(n_pad):
-                for lst in (xs, ys, masks, perms):
-                    lst.append(lst[-1])
-                if stacked_init:
-                    inits.append(inits[-1])
-            xs, ys, masks, perms = (np.stack(a) for a in (xs, ys, masks, perms))
             if stacked_init:
+                inits = [req.init_params if req.init_params is not None
+                         else global_params for req in reqs]
+                inits += inits[-1:] * n_pad
                 count_d2h("train", inits)
                 p0 = jax.tree.map(
                     lambda *ls: np.stack([np.asarray(l) for l in ls]), *inits)
         with span("train.upload"):
-            xs, ys, masks, perms = (jnp.asarray(a) for a in (xs, ys, masks, perms))
+            # uploaded by the first stage over this data and placement
+            x, y = data.resident_train(self.mesh)
+            rows = jnp.asarray(rows)
             if stacked_init:
                 p0 = jax.tree.map(jnp.asarray, p0)
-                count_h2d("train", xs, ys, masks, perms, p0)
+                count_h2d("train", rows, p0)
             else:
                 # shared start (probe stage / vanilla rounds): pass the
                 # single pytree and let vmap broadcast it inside XLA — no
                 # K-fold copy; host leaves cross as the step's operands
                 p0 = global_params
-                count_h2d("train", xs, ys, masks, perms)
-                count_implicit_h2d("train", p0, xs)
+                count_h2d("train", rows)
+                count_implicit_h2d("train", p0, rows)
             step = _bucket_step(task, bs, nb, epochs, float(prox_mu),
                                 stacked_init)
-            xs, ys, masks, perms = self._shard((xs, ys, masks, perms))
+            rows = self._shard(rows)
             p0 = self._shard_params(p0, stacked_init)
             lr = jnp.asarray(lr, jnp.float32)
             count_h2d("train", lr)
@@ -308,7 +324,7 @@ class VmappedExecutor:
         # fenced and charged per (cohort-size, epochs) geometry
         stacked, ep_losses = timed_call(
             f"vmapped.bucket_step[k={len(reqs)},ep={epochs}]",
-            step, p0, xs, ys, masks, lr, perms)
+            step, p0, x, y, rows, lr)
         # one device->host transfer per leaf, then cheap numpy views per
         # client — slicing on device would cost K x leaves dispatches
         with span("train.pull"):

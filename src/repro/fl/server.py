@@ -458,10 +458,6 @@ class FLServer:
             telemetry=self.telemetry, feature_set=self.feature_set,
             region=self.pool.region, rng=self.rng)
 
-    def _client_data(self, i: int):
-        idx = self.data.client_indices[i]
-        return self.data.train.x[idx], self.data.train.y[idx]
-
     def _execute(self, requests: Sequence[ClientRequest]):
         # a passthrough unless a profiler is active: then the result is
         # fenced, so device work is charged to this executor call rather
@@ -503,7 +499,7 @@ class FLServer:
         if plan.has_probe:
             with obs.span("probe"):
                 self._check_available(ctx, probe_ids, policy, "probed")
-                reqs = build_requests(probe_ids, self._client_data,
+                reqs = build_requests(probe_ids, self.data,
                                       plan.probe_epochs, seed=cfg.seed,
                                       round_idx=ctx.round,
                                       stride=PROBE_SEED_STRIDE)
@@ -541,7 +537,7 @@ class FLServer:
         # ---- completion stage (survivors only) -----------------------
         with obs.span("complete"):
             if plan.completion_epochs > 0 and len(survivors):
-                reqs = build_requests(survivors, self._client_data,
+                reqs = build_requests(survivors, self.data,
                                       plan.completion_epochs, seed=cfg.seed,
                                       round_idx=ctx.round,
                                       stride=COMPLETE_SEED_STRIDE,

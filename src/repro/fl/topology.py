@@ -398,7 +398,7 @@ def run_topology_round(srv, policy):
         srv._check_available(g["ctx"], g["probe_ids"], policy, "probed")
     if probing:
         with obs.span("probe"):
-            groups = [build_requests(g["probe_ids"], srv._client_data,
+            groups = [build_requests(g["probe_ids"], srv.data,
                                      g["plan"].probe_epochs, seed=cfg.seed,
                                      round_idx=base_ctx.round,
                                      stride=PROBE_SEED_STRIDE)
@@ -442,7 +442,7 @@ def run_topology_round(srv, policy):
 
     # ---- completion stage (one stacked executor call) ----------------
     with obs.span("complete"):
-        groups = [build_requests(g["survivors"], srv._client_data,
+        groups = [build_requests(g["survivors"], srv.data,
                                  g["plan"].completion_epochs, seed=cfg.seed,
                                  round_idx=base_ctx.round,
                                  stride=COMPLETE_SEED_STRIDE,

@@ -16,7 +16,9 @@ module global: a server whose recorder is enabled registers it with
 * :func:`count_h2d`, :func:`count_implicit_h2d` and :func:`count_d2h` add
   the bytes that cross the host link, and the blocking reads, to the
   recorder's ``h2d_bytes.<layer>`` / ``d2h_bytes.<layer>`` /
-  ``host_syncs.<layer>`` counters;
+  ``host_syncs.<layer>`` counters; :func:`count_resident_upload` counts
+  the one-time upload of the device-resident training rows
+  (``h2d_bytes.resident``, ``resident_uploads``);
 * one ``jax.monitoring`` listener, registered once per process, adds 1 to
   ``compiles.<span path>`` per XLA compile (or persistent-cache load).
 
@@ -130,6 +132,17 @@ def count_implicit_h2d(layer: str, *operands) -> None:
     if any(_is_device(leaf) for leaf in leaves):
         host = [leaf for leaf in leaves if not _is_device(leaf)]
         prof.metrics.count(f"h2d_bytes.{layer}", _tree_nbytes(host))
+
+
+def count_resident_upload(*trees) -> None:
+    """One-time upload of data that stays on the device (the training
+    rows): its bytes under ``h2d_bytes.resident`` and 1 under
+    ``resident_uploads``."""
+    prof = _ACTIVE
+    if prof is None:
+        return
+    prof.metrics.count("h2d_bytes.resident", _tree_nbytes(_leaves(trees)))
+    prof.metrics.count("resident_uploads")
 
 
 def count_d2h(layer: str, *trees) -> None:

@@ -106,13 +106,11 @@ def test_random_policy_name_distinct():
 def test_vmapped_executor_with_mesh_matches_sequential(mlp_task, fl_data):
     """Mesh-backed VmappedExecutor (1-device host mesh, clients on 'data')
     still matches the sequential reference."""
-    from repro.fl.engine import ClientRequest, SequentialExecutor, VmappedExecutor
+    from repro.fl.engine import SequentialExecutor, VmappedExecutor, build_requests
     from repro.launch.mesh import make_host_mesh
 
     gp = mlp_task.init(jax.random.PRNGKey(0))
-    reqs = [ClientRequest(c, fl_data.train.x[fl_data.client_indices[c]],
-                          fl_data.train.y[fl_data.client_indices[c]],
-                          epochs=2, seed=c) for c in range(3)]
+    reqs = build_requests(range(3), fl_data, 2, seed=0, round_idx=0, stride=0)
     kw = dict(lr=0.1, batch_size=32, prox_mu=0.0)
     seq = SequentialExecutor().run(mlp_task, gp, reqs, **kw)
     par = VmappedExecutor(mesh=make_host_mesh()).run(mlp_task, gp, reqs, **kw)

@@ -454,22 +454,20 @@ def test_vmapped_round_records_train_subspans(mlp_task, fl_data, mode,
 
 
 def _cohort_h2d(requests, batch_size, params_host_bytes=0):
-    """The bytes the vmapped executor uploads for ``requests``: per bucket
-    the padded rows, labels, mask and shuffle orders, the learning rate,
-    and the shared start's host leaves."""
-    from repro.fl.client import _bucket_geometry, _pad_bucket
+    """The bytes the vmapped executor uploads for ``requests`` (the rows
+    themselves stay resident): per bucket each client's int32 row indices
+    for every epoch, the learning rate, and the shared start's host
+    leaves."""
+    from repro.fl.client import _bucket_geometry
 
     buckets = {}
     for req in requests:
-        cap, _, _ = _bucket_geometry(len(req.y), batch_size)
+        cap, _, _ = _bucket_geometry(len(req.rows), batch_size)
         buckets.setdefault((cap, req.epochs), []).append(req)
     total = 0
     for (cap, epochs), reqs in buckets.items():
         _, bs, nb = _bucket_geometry(cap, batch_size)
-        for req in reqs:
-            xpad, ypad, mask = _pad_bucket(req.x, req.y)
-            total += xpad.nbytes + ypad.nbytes + mask.nbytes
-            total += epochs * nb * bs * 4          # int32 shuffle orders
+        total += len(reqs) * epochs * nb * bs * 4  # int32 row indices
         total += 4 + params_host_bytes             # float32 lr, start
     return total, len(buckets)
 
@@ -488,11 +486,7 @@ def test_h2d_train_counts_the_cohort(mlp_task, fl_data, host_params):
                   if host_params else 0)
     ids = [0, 3, 5, 11]
 
-    def rows(i):
-        idx = fl_data.client_indices[i]
-        return fl_data.train.x[idx], fl_data.train.y[idx]
-
-    reqs = build_requests(ids, rows, 2, seed=7, round_idx=0, stride=1000)
+    reqs = build_requests(ids, fl_data, 2, seed=7, round_idx=0, stride=1000)
     rec = RunRecorder()
     set_profiler(rec)
     VmappedExecutor().run(mlp_task, params, reqs, lr=0.1, batch_size=10,
@@ -504,6 +498,62 @@ def test_h2d_train_counts_the_cohort(mlp_task, fl_data, host_params):
     # one blocking read per stacked leaf and one for the losses, per bucket
     n_leaves = len(jax.tree.leaves(params))
     assert counters["host_syncs.train"] == n_buckets * (n_leaves + 1)
+
+
+def test_requests_read_the_clients_rows(fl_data):
+    import numpy as np
+
+    from repro.fl.engine import build_requests
+
+    reqs = build_requests([2, 9, 17], fl_data, 1, seed=0, round_idx=0,
+                          stride=0)
+    for req in reqs:
+        idx = fl_data.client_indices[req.client_id]
+        assert req.rows is idx                 # the index array, not a copy
+        np.testing.assert_array_equal(req.x, fl_data.train.x[idx])
+        np.testing.assert_array_equal(req.y, fl_data.train.y[idx])
+
+
+def test_resident_rows_upload_once(mlp_task):
+    """Three observed rounds upload the training rows once, in the first
+    stage; every stage after uploads row indices, lr and host params."""
+    import jax
+    import numpy as np
+
+    from repro.data import FederatedData, dirichlet_partition, \
+        make_classification_data
+    from repro.fl.engine import VmappedExecutor
+
+    train, test = make_classification_data(n_samples=1200, seed=4)
+    data = FederatedData(train, test,
+                         dirichlet_partition(train.y, 12, sigma=0.5, seed=4))
+    calls = []
+
+    class Spy(VmappedExecutor):
+        def run(self, task, global_params, requests, **kw):
+            host = sum(l.nbytes for l in jax.tree.leaves(global_params)
+                       if not isinstance(l, jax.Array))
+            calls.append((len(srv.history), list(requests), host))
+            return super().run(task, global_params, requests, **kw)
+
+    rec = RunRecorder()
+    cfg = FLConfig(n_devices=12, k_select=3, rounds=3, l_ep=2, lr=0.1,
+                   seed=4, observe=rec)
+    srv = FLServer(cfg, mlp_task, data, executor=Spy())
+    srv.run(build_policy("fedavg"))
+    rounds = _round_records(rec)
+    assert len(rounds) == 3
+    counters = [r["metrics"]["counters"] for r in rounds]
+    assert sum(c.get("resident_uploads", 0) for c in counters) == 1
+    assert counters[0]["resident_uploads"] == 1
+    n1 = len(train.y) + 1                      # rows + the padding row
+    padded = n1 * train.x.shape[1] * 4 + n1 * 4
+    assert sum(c.get("h2d_bytes.resident", 0) for c in counters) == padded
+    for r, c in enumerate(counters):
+        want = sum(_cohort_h2d(reqs, cfg.local_batch, host)[0]
+                   for i, reqs, host in calls if i == r)
+        assert want > 0
+        assert c["h2d_bytes.train"] == want
 
 
 def test_host_syncs_evaluate_two_per_batch(mlp_task, fl_data):

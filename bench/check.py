@@ -41,31 +41,113 @@ kind of answer:
 ``numbers(..., control=prec)`` reads the same numbers for the control: the
 reference computed at the precision ``prec`` below the configuration's,
 put in the program's place.
+
+Parameter trees may nest dicts, lists and tuples (a transformer's
+``layers/attn/wq``); :func:`leaves` names each leaf by its path, and a flat
+dict is its own flattening.  What the program and the task module return
+may be NumPy or ``jax.Array`` leaves in any float dtype (the contract of a
+task module is in :mod:`bench.reference`).  Host memory stays bounded
+whatever the model's size: no tree is brought to the host whole in
+float64.  The reference trains the clients of a group in chunks of at most
+``CHUNK_BYTES`` of float64 params, handed the start params as they were
+recorded (a view, at one client a chunk); each norm and each leaf of
+the merge is taken on blocks of at most ``ROW`` elements a client,
+brought to the host in float64 only there; a client's reference state is
+kept only for a later call of the same aggregation that resumes it.  So a
+compared aggregation of a model of B float32 bytes adds one chunk of the
+reference's output to the host's peak (B in float32, 2B in float64, at
+one client a chunk) and a few blocks, whatever the number of clients.
 """
 from __future__ import annotations
 
 import numpy as np
 
+from bench import fleet
 from bench import reference as R
-from bench.fleet import module
 
 # The control's precision, by the configuration's matmul precision (float32
 # params): bfloat16 is the step below float32 at ``default``.
 CONTROL = {"default": R.BF16}
 
+# float64 bytes of params that one call of the reference trains: the MLP's
+# groups of up to 50 clients (80 MB) take one call; a model of 12.6M
+# parameters or more trains one client a call
+CHUNK_BYTES = 96 << 20
+# elements of one client's leaf in one block of a norm or a merge (8 MiB in
+# float64): every leaf of the MLP is one block
+ROW = 1 << 20
 
-def _host(tree) -> dict:
-    return {k: np.asarray(v, np.float64) for k, v in dict(tree).items()}
+
+def _path(prefix: str, key) -> str:
+    return f"{prefix}/{key}" if prefix else str(key)
 
 
-def _worst_leaf(p_start: dict, p_ref: dict, p: dict) -> float:
-    upd = {k: np.linalg.norm(p_ref[k] - p_start[k]) for k in p_ref}
+def leaves(tree, prefix: str = "") -> dict:
+    """``{path: leaf}`` of a tree of dicts, lists and tuples, in the tree's
+    own order: keys and indices joined by ``/``."""
+    if isinstance(tree, dict):
+        items = tree.items()
+    elif isinstance(tree, (list, tuple)):
+        items = enumerate(tree)
+    else:
+        return {prefix: tree}
+    out = {}
+    for k, v in items:
+        out.update(leaves(v, _path(prefix, k)))
+    return out
+
+
+def rebuild(tree, leaf, prefix: str = ""):
+    """``tree``'s structure with the leaf at each path replaced by
+    ``leaf(path)``."""
+    if isinstance(tree, dict):
+        return {k: rebuild(v, leaf, _path(prefix, k)) for k, v in tree.items()}
+    if isinstance(tree, (list, tuple)):
+        return type(tree)(rebuild(v, leaf, _path(prefix, i))
+                          for i, v in enumerate(tree))
+    return leaf(prefix)
+
+
+def _stack(arrays: list):
+    """The leaves stacked on a new client axis, in their dtype: one leaf
+    stays where it lies (a view for NumPy), several are stacked on the
+    host.  Not a broadcast view: a copy made of one lays the clients
+    innermost, which changes the order of the reference's sums."""
+    if len(arrays) == 1:
+        return arrays[0][None]
+    return np.stack(arrays)
+
+
+def _blocks(n: int) -> list:
+    """Slices of a leaf of ``n`` elements a client, at most ``ROW`` each;
+    ``slice(None)`` where the leaf is one block."""
+    if n <= ROW:
+        return [slice(None)]
+    return [slice(lo, lo + ROW) for lo in range(0, n, ROW)]
+
+
+def _cut(a, s: slice, c: int = 1) -> np.ndarray:
+    """Block ``s`` of each client's elements of leaf ``a`` (one client's, or
+    ``c`` stacked), ``(c, block)`` on the host in the leaf's dtype."""
+    if s != slice(None):
+        a = a.reshape(c, -1)[:, s]       # cut where the leaf lies
+    return np.asarray(a).reshape(c, -1)
+
+
+def _f64(a, s: slice, c: int = 1) -> np.ndarray:
+    return np.asarray(_cut(a, s, c), np.float64)
+
+
+def _worst_leaf(upd: dict, dev: dict) -> float:
+    """Over the leaves, the worst ``dev / upd`` (the norms of ``p - p_ref``
+    and ``p_ref - p_start``), leaves whose update is under a thousandth of
+    the median leaf's left out."""
     med = float(np.median(list(upd.values())))
     worst = 0.0
     for k, u in upd.items():
         if u < 1e-3 * med:
             continue
-        d = float(np.linalg.norm(np.asarray(p[k], np.float64) - p_ref[k]))
+        d = dev[k]
         # a reference that did not move at all: any departure is infinite
         worst = max(worst, d / u if u > 0 else (0.0 if d == 0 else np.inf))
     return worst
@@ -85,59 +167,93 @@ def offline(avail: list, trace_view) -> int:
 
 def _train(rec, index, init_params, task, config, control, out):
     lr, bs = config["client"]["lr"], config["client"]["local_batch"]
-    ref_state, ctl_state = {}, {}
+    resumed_later = {cid for _, reqs, _ in rec["train"]
+                     for cid, _, _, _, resumed, _ in reqs if resumed}
+    ref_state, ctl_state = {}, {}        # path -> leaf, by client
     steps = []                           # each client's worst-leaf gap
     for g_in, reqs, outs in rec["train"]:
-        g = _host(init_params if index == 0 else g_in)
+        g = init_params if index == 0 else g_in
+        g_leaves = leaves(g)
+        per_chunk = max(1, CHUNK_BYTES // (8 * sum(
+            int(np.size(v)) for v in g_leaves.values())))
         groups = {}
         for cid, x, y, epochs, resumed, seed in reqs:
             groups.setdefault((epochs, resumed, len(y)), []).append(
-                (cid, R.feed(x, y, seed, epochs, bs)))
+                (cid, x, y, seed))
         for (epochs, resumed, n), group in groups.items():
-            cids = [c for c, _ in group]
-            xs, ys, masks, orders = (np.stack(a) for a in
-                                     zip(*[fd for _, fd in group]))
-            rows = (xs[:, :n], ys[:, :n])      # the real rows, unpadded
+            for lo in range(0, len(group), per_chunk):
+                chunk = group[lo:lo + per_chunk]
+                cids = [c for c, *_ in chunk]
+                xs, ys, masks, orders = (np.stack(a) for a in zip(*[
+                    R.feed(x, y, seed, epochs, bs)
+                    for _, x, y, seed in chunk]))
 
-            def start(state):
-                return {k: np.stack([state[c][k] if resumed else g[k]
-                                     for c in cids]) for k in g}
+                def start(state):
+                    if resumed:
+                        return rebuild(g, lambda k: _stack(
+                            [state[c][k] for c in cids]))
+                    return rebuild(g, lambda k: _stack(
+                        [g_leaves[k]] * len(cids)))
 
-            p0 = start(ref_state)
-            ref = task.train(p0, xs, ys, masks, orders, lr)
-            for j, c in enumerate(cids):
-                ref_state[c] = {k: v[j] for k, v in ref.items()}
-            if control is None:
-                got = {c: outs[c] for c in cids}
-            else:
-                ctl = task.train(start(ctl_state), xs, ys, masks, orders,
-                                 lr, control)
-                for j, c in enumerate(cids):
-                    ctl_state[c] = {k: v[j] for k, v in ctl.items()}
-                got = {c: ctl_state[c] for c in cids}
-            got = {k: np.stack([np.asarray(got[c][k], np.float64)
-                                for c in cids]) for k in g}
-            steps.extend(_step_gap(p0, ref, got))
-            if index != 0:
-                continue
-            l0, l_ref = task.loss(p0, *rows), task.loss(ref, *rows)
-            l_got = task.loss(got, *rows)
-            # no training to do (zero epochs): the params come back as handed
-            moved = np.abs(l0 - l_ref)
-            gap = np.where(moved > 0, np.abs(l_got - l_ref)
-                           / np.where(moved > 0, moved, 1.0),
-                           np.where(l_got == l_ref, 0.0, np.inf))
-            out["fit_gap"] = max(out["fit_gap"], float(np.max(gap)))
+                p0 = start(ref_state)
+                ref = task.train(p0, xs, ys, masks, orders, lr)
+                ref_leaves = leaves(ref)
+                _keep(ref_state, cids, ref_leaves, resumed_later)
+                if control is None:
+                    got = [leaves(outs[c]) for c in cids]
+                else:
+                    ctl = leaves(task.train(start(ctl_state), xs, ys, masks,
+                                            orders, lr, control))
+                    _keep(ctl_state, cids, ctl, resumed_later)
+                    got = [{k: v[j] for k, v in ctl.items()}
+                           for j in range(len(cids))]
+                steps.extend(_step_gap(leaves(p0), ref_leaves, got))
+                if index == 0:
+                    _fit_gap(task, p0, ref, rebuild(g, lambda k: _stack(
+                        [t[k] for t in got])), (xs[:, :n], ys[:, :n]), out)
+                # freed before the next chunk trains
+                del p0, ref, ref_leaves, got
     if steps:
         out["step_gap"] = max(out["step_gap"], float(np.median(steps)))
 
 
-def _step_gap(p0: dict, ref: dict, got: dict) -> np.ndarray:
+def _keep(state: dict, cids: list, stacked: dict, wanted: set) -> None:
+    """Keep the clients' rows of ``stacked`` that a later call resumes from,
+    each its own copy."""
+    for j, c in enumerate(cids):
+        if c in wanted:
+            state[c] = {k: np.array(v[j]) if isinstance(v, np.ndarray)
+                        else v[j] for k, v in stacked.items()}
+
+
+def _fit_gap(task, p0, ref, got, rows, out) -> None:
+    """``|L(p) - L(p_ref)| / |L(p_start) - L(p_ref)|`` of a chunk's clients
+    on their real, unpadded rows."""
+    l0, l_ref, l_got = (np.asarray(task.loss(p, *rows), np.float64)
+                        for p in (p0, ref, got))
+    # no training to do (zero epochs): the params come back as handed
+    moved = np.abs(l0 - l_ref)
+    gap = np.where(moved > 0, np.abs(l_got - l_ref)
+                   / np.where(moved > 0, moved, 1.0),
+                   np.where(l_got == l_ref, 0.0, np.inf))
+    out["fit_gap"] = max(out["fit_gap"], float(np.max(gap)))
+
+
+def _step_gap(p0: dict, ref: dict, got: list) -> np.ndarray:
     """(C,) each client's worst leaf: ``| |got - p0| - |ref - p0| |`` over
-    the larger of ``|ref - p0|`` and the median leaf's (leaves (C, ...))."""
-    axes = {k: tuple(range(1, v.ndim)) for k, v in ref.items()}
-    r = {k: np.sqrt(((ref[k] - p0[k]) ** 2).sum(axes[k])) for k in ref}
-    d = {k: np.sqrt(((got[k] - p0[k]) ** 2).sum(axes[k])) for k in ref}
+    the larger of ``|ref - p0|`` and the median leaf's.  ``p0`` and ``ref``
+    map paths to leaves stacked over the C clients, ``got`` holds each
+    client's ``{path: leaf}``; every difference is taken in float64."""
+    c = len(got)
+    r, d = {}, {}
+    for k in ref:
+        r2 = d2 = 0.0
+        for s in _blocks(int(np.size(ref[k])) // c):
+            start = _f64(p0[k], s, c)
+            r2 = r2 + ((_f64(ref[k], s, c) - start) ** 2).sum(1)
+            mine = np.concatenate([_f64(t[k], s) for t in got])
+            d2 = d2 + ((mine - start) ** 2).sum(1)
+        r[k], d[k] = np.sqrt(r2), np.sqrt(d2)
     med = np.median(np.stack(list(r.values())), axis=0)
     worst = np.zeros_like(med)
     for k in ref:
@@ -148,14 +264,37 @@ def _step_gap(p0: dict, ref: dict, got: dict) -> np.ndarray:
     return worst
 
 
+def _merge_err(merge_leaf, g, clients: list, weights, lags, traffic,
+               merged, control) -> float:
+    """The merge's worst leaf against the reference merge of the same
+    uploads, one block of one leaf at a time."""
+    ups = [leaves(c) for c in clients]
+    got = leaves(merged) if control is None else None
+    upd, dev = {}, {}
+    for k, gk in leaves(g).items():
+        u2 = d2 = 0.0
+        for s in _blocks(int(np.size(gk))):
+            g_blk = _cut(gk, s)[0]
+            c_blk = [_cut(c[k], s)[0] for c in ups]
+            ref = merge_leaf(g_blk, c_blk, weights, lags, traffic)
+            mine = (_f64(got[k], s)[0] if control is None else
+                    merge_leaf(g_blk, c_blk, weights, lags, traffic, control))
+            x = ref - np.asarray(g_blk, np.float64)
+            u2 += x.dot(x)
+            x = np.asarray(mine, np.float64) - ref
+            d2 += x.dot(x)
+        upd[k], dev[k] = np.sqrt(u2), np.sqrt(d2)
+    return _worst_leaf(upd, dev)
+
+
 def numbers(kept: dict, avail: list, init_params, config: dict,
             traffic: dict, trace_view, control=None) -> dict:
     """The compared numbers over the kept records (see module doc)."""
-    task = module("tasks", config["model"]["task"])
-    merge = module("merges", traffic["aggregator"]).merge
+    task = fleet.module("tasks", config["model"]["task"])
+    merge_leaf = fleet.module("merges", traffic["aggregator"]).merge_leaf
     out = {"fit_gap": 0.0, "step_gap": 0.0, "merge_err": 0.0,
            "offline": offline(avail, trace_view)}
-    out.update(module("policies", traffic["policy"]).numbers(
+    out.update(fleet.module("policies", traffic["policy"]).numbers(
         kept, traffic, control))
     for index, rec in sorted(kept.items()):
         _train(rec, index, init_params, task, config, control, out)
@@ -170,14 +309,10 @@ def numbers(kept: dict, avail: list, init_params, config: dict,
             # its result is not the global model that the server went on with
             out["merge_err"] = float("inf")
             continue
-        g = _host((init_params if index == 0 else rec["g_in"])
-                  if g_in is None else g_in)
-        clients = [_host(c) for c in clients]
-        ref = merge(g, clients, weights, lags, traffic)
-        got = (merged if control is None else
-               merge(g, clients, weights, lags, traffic, control))
-        out["merge_err"] = max(out["merge_err"],
-                               _worst_leaf(g, ref, _host(got)))
+        g = ((init_params if index == 0 else rec["g_in"]) if g_in is None
+             else g_in)
+        out["merge_err"] = max(out["merge_err"], _merge_err(
+            merge_leaf, g, clients, weights, lags, traffic, merged, control))
     return out
 
 
@@ -204,4 +339,5 @@ class TraceView:
 
 
 def is_finite(tree) -> bool:
-    return all(np.isfinite(np.asarray(v)).all() for v in dict(tree).values())
+    return all(np.isfinite(np.asarray(v)).all()
+               for v in leaves(tree).values())

@@ -12,6 +12,26 @@ Also here, because every task is fed by it: the client-data feed of the
 server's executors (shards padded with masked rows to a power of two, at
 least 8; per epoch a shuffle of the padded rows drawn from the request's
 seed, cut to whole batches), and the availability trace.
+
+What :mod:`bench.check` asks of a task module's reference
+(``bench/tasks/<model.task>.py``):
+
+* ``train(p0, x, y, mask, order, lr, prec=F64)`` -- local training of a
+  chunk of C clients.  ``p0`` has the structure of the program's params
+  (nested dicts, lists and tuples), each leaf stacked over the C clients,
+  in the dtype and the kind of array it was recorded in (NumPy or
+  ``jax.Array``); at one client a chunk it is a view of what was
+  recorded, so it is read, never written.  It returns the trained params
+  in the same structure, as NumPy or device arrays.
+* ``loss(p, x, y)`` -- the (C,) loss of each client's params on its rows,
+  as NumPy or device arrays.
+* The module states in its docstring which reference it computes: float64
+  NumPy, as here, or -- where that could not finish within a run, as for a
+  transformer at its published widths -- straightforward ``jax.numpy`` in
+  float32 under ``jax.default_matmul_precision("highest")``, computed in
+  blocks so that it fits.  Either way it imports nothing of the program,
+  and its control is the step one precision below the configuration's
+  (:data:`bench.check.CONTROL`).
 """
 from __future__ import annotations
 

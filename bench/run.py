@@ -27,6 +27,7 @@ T_PROCESS = time.perf_counter()
 import argparse  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
+import resource  # noqa: E402
 import shutil  # noqa: E402
 import statistics  # noqa: E402
 import sys  # noqa: E402
@@ -212,8 +213,12 @@ def main(argv=None) -> int:
     failed += 0 if finite else 1
     ok = (failed == 0 and compared >= 1
           and all(nums[k] <= limits[k] for k in limits))
+    # the process's host peak so far (KiB on Linux): what a cell's data,
+    # program and check cost the host
+    rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2**20
     print(f"check: {compared} window aggregations compared in "
-          f"{time.perf_counter() - t_check:.1f} s; final params "
+          f"{time.perf_counter() - t_check:.1f} s; host peak RSS "
+          f"{rss:.2f} GiB; final params "
           f"{'finite' if finite else 'NOT finite'}", file=sys.stderr)
 
     # ---- metrics ---------------------------------------------------------------

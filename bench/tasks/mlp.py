@@ -5,7 +5,7 @@ What the harness needs of a task, found by the name in a configuration's
 ``model.task``: the program's task object, the initial weights as a pure
 function of a key, the FLOPs the work needs, and the plain reference of a
 client's local training (masked mean softmax cross-entropy, SGD on each
-batch of the feed in :func:`bench.reference.feed`).
+batch of the feed in :func:`bench.reference.feed`), in float64 NumPy.
 """
 from __future__ import annotations
 

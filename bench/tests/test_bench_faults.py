@@ -152,5 +152,6 @@ def test_step_gap_of_a_scaled_update(scale, reads):
     ref = {k: v + 10.0 ** -i * rng.normal(size=v.shape)
            for i, (k, v) in enumerate(p0.items())}
     got = {k: p0[k] + scale * (ref[k] - p0[k]) for k in p0}
-    np.testing.assert_allclose(_step_gap(p0, ref, got), [reads] * 3,
+    per_client = [{k: v[j] for k, v in got.items()} for j in range(3)]
+    np.testing.assert_allclose(_step_gap(p0, ref, per_client), [reads] * 3,
                                atol=1e-12)

@@ -27,6 +27,11 @@ def test_names_units_and_bounds():
     assert runs * (SPEC["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
 
 
+def test_each_pair_of_config_and_traffic_once():
+    pairs = [(c["config"], c["traffic"]) for c in SPEC["workloads"]]
+    assert len(pairs) == len(set(pairs))
+
+
 @pytest.mark.parametrize("cell", SPEC["workloads"], ids=lambda c: c["name"])
 def test_cell_files(cell):
     spec = json.loads((ROOT / "bench" / "workloads" /
